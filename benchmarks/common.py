@@ -1,11 +1,13 @@
 """Shared benchmark utilities.
 
-IMPORTANT CONTEXT (recorded in every CSV): this container is CPU-only.
-Wall-clock numbers are XLA-CPU timings of the *pure-JAX* chained-MMA
-reduction (repro.core) vs the classic `jnp.sum`; they demonstrate the
-harness, not TPU performance.  TPU-relevant evidence is (a) the PRAM
-cost model (core.theory), (b) HLO op/flop accounting, and (c) the
-precision experiments (bit-exact bf16 on any backend).
+These drivers time whatever backend JAX runs on, and every CSV records
+it.  On the CPU (where the tests run, with the Pallas kernels in
+interpret mode) the numbers time XLA-CPU and the Pallas interpreter:
+they exercise the harness and say nothing about speed on a TPU.  On a
+TPU host the same drivers run the compiled kernels; ``chip_smoke.py``
+at the repo root is the check that the main path runs there.  The
+model-unit numbers (the PRAM cost model in ``core.theory``, HLO
+op/flop accounting) and the precision experiments hold on any backend.
 """
 
 from __future__ import annotations

@@ -1,9 +1,6 @@
-"""Version shims for the JAX APIs that moved between releases.
-
-The framework targets the modern names (``jax.shard_map``,
-``jax.sharding.AxisType``); on older installs (<= 0.4.x) those live in
-``jax.experimental.shard_map`` / don't exist, so every call site routes
-through this module instead of feature-detecting inline.
+"""The two JAX calls every mesh call site shares, with this repo's
+defaults: ``shard_map`` with the replication check off and meshes with
+explicit ``Auto`` axis types.
 """
 
 from __future__ import annotations
@@ -12,29 +9,14 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` when available, else the experimental spelling.
-
-    The old API names the replication check ``check_rep``; the new one
-    ``check_vma``.  Semantics are the same for our usage (we always
-    disable it: the MoE body mixes psum'd and per-shard outputs).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=bool(check_vma))
+    """``jax.shard_map`` with the replication check off by default (the
+    MoE body mixes psum'd and per-shard outputs)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with explicit-Auto axis types when the install
-    supports them (newer JAX), plain otherwise (axes default to Auto)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                axis_shapes, axis_names,
-                axis_types=(axis_type.Auto,) * len(axis_names))
-        except TypeError:
-            pass  # make_mesh predates the axis_types kwarg
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with every axis typed ``Auto``."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))

@@ -54,6 +54,7 @@ import contextlib
 import dataclasses
 import functools
 import json
+import logging
 import math
 import os
 import queue
@@ -71,6 +72,8 @@ except ImportError:  # pragma: no cover - non-POSIX host
     fcntl = None
 
 from repro.core import theory
+
+log = logging.getLogger(__name__)
 
 # The paper's experimental sweep: chain length R (Figs. 3/5) and block
 # geometry B (threads/block on GPU -> rows per VMEM tile here).
@@ -1341,6 +1344,8 @@ class SweepWorker:
     resubmitted on the next model-plan serve), and ``close()`` sets
     the stop flag, drains the queue, and joins with a timeout, so a
     server shutdown can never deadlock on an in-flight sweep.
+    ``upgraded`` and ``failed`` count finished and failed sweeps; each
+    failure is also logged with its traceback.
     """
 
     def __init__(self, registry=None, *, max_pending: int = 256,
@@ -1414,9 +1419,13 @@ class SweepWorker:
             except SweepCancelled:
                 pass  # shutdown raced the sweep; model plan keeps serving
             except Exception:
-                # Best-effort: a failed sweep (e.g. a mesh plan on a
-                # host without that mesh) keeps the model plan serving.
+                # A failed sweep (e.g. a mesh plan on a host without
+                # that mesh, or a kernel the backend refuses) keeps the
+                # model plan serving, but is counted in ``failed`` and
+                # logged with its traceback, never swallowed.
                 self.failed += 1
+                log.exception("autotune sweep for %s failed; the model "
+                              "plan keeps serving", key)
             finally:
                 with self._mu:
                     self._inflight.discard(key)

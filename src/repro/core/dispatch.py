@@ -951,9 +951,9 @@ def _nm_fused(x, plan, *, w, scale, w_gate=None, bias=None, act=None,
                            block_rows=plan.block_rows)
 
 
-# The fused kernel walks d in 128-lane k-blocks while holding the
-# (rows, dout) f32 accumulator in VMEM; past this padded width the
-# weight tile + accumulator working set blows the 16 MB budget.
+# The fused kernel tiles d and dout, so VMEM does not bound d_model;
+# the engine still serves only models up to this padded width until a
+# chip measurement shows it pays at published widths.
 _NM_FUSED_MAX_D = 512
 
 
@@ -961,8 +961,8 @@ def _nm_fused_predicate(ctx: DispatchContext) -> Optional[str]:
     pad = -(-max(int(ctx.extra("d_model", 0)), 1) // 128) * 128
     if pad > _NM_FUSED_MAX_D:
         return (f"padded d_model {pad} exceeds the fused norm->matmul "
-                f"kernel's {_NM_FUSED_MAX_D}-lane VMEM k-block tiling; "
-                f"use the unfused engines")
+                f"engine's {_NM_FUSED_MAX_D}-lane limit; use the unfused "
+                f"engines")
     return None
 
 

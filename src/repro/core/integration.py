@@ -241,8 +241,9 @@ def segment_sum(values, segment_ids, num_segments: int, *,
 
     'mma' contracts against the one-hot segment matrix (block-diagonal
     for sorted ids — ``repro.core.scan.tc_segment_reduce``); 'pallas'
-    builds the mask in-kernel; 'vpu' is the ``jax.ops.segment_sum``
-    scatter-add baseline; 'auto' consults the registry under
+    masks each tile to one segment at a time in-kernel; 'vpu' is the
+    ``jax.ops.segment_sum`` scatter-add baseline; 'auto' consults the
+    registry under
     op='segment_sum'.  Empty segments are 0.  (num_segments,) f32.
     """
     return dispatch.dispatch("segment_sum", values, method=method,

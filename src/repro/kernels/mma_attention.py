@@ -2,12 +2,13 @@
 statistics (ROADMAP open item 1; registered as the ``attention`` op's
 ``fused_pallas`` engine in ``repro.core.dispatch``).
 
-One kernel instance owns a (batch, kv-head, group) cell of the grid and
-walks the KV sequence in ``block_rows``-sized blocks (the sequential
-innermost grid axis).  Per block it computes the score tile on the MXU,
-then folds the online-softmax row statistics *inside the kernel* — the
-gap Dakkak et al. (arXiv:1811.09736) identify: reductions fused into
-the surrounding TCU kernel instead of separate passes around it:
+One kernel instance owns a (batch, kv-head, group, query-tile) cell of
+the grid and walks the KV sequence in ``block_rows``-sized blocks (the
+sequential innermost grid axis).  Per block it computes the score tile
+on the MXU, then folds the online-softmax row statistics *inside the
+kernel* — the gap Dakkak et al. (arXiv:1811.09736) identify:
+reductions fused into the surrounding TCU kernel instead of separate
+passes around it:
 
   * the running **row max** via a chained max-fold over ``chain``
     sub-slices of the block (the max variant of the paper's chain);
@@ -55,16 +56,17 @@ _M_INIT = -1.0e30
 
 _LANES = 128     # MXU/VPU lane width: head dims pad to it, the ones
 #                  contraction folds onto it
+_Q_TILE = 512    # query rows per grid cell
 
 
 def _ceil_to(n: int, m: int) -> int:
     return -(-int(n) // m) * m
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, qpos_ref, kvlen_ref, o_ref,
+def _attn_kernel(kvlen_ref, q_ref, k_ref, v_ref, qpos_ref, o_ref,
                  m_s, l_s, c_s, acc_s, *, blk, chain, scale, cap,
                  causal, window, has_kvlen, sk):
-    j = pl.program_id(3)
+    j = pl.program_id(4)
 
     @pl.when(j == 0)
     def _init():
@@ -73,14 +75,14 @@ def _attn_kernel(q_ref, k_ref, v_ref, qpos_ref, kvlen_ref, o_ref,
         c_s[...] = jnp.zeros(c_s.shape, ACCUM_DTYPE)
         acc_s[...] = jnp.zeros(acc_s.shape, ACCUM_DTYPE)
 
-    q = q_ref[0, 0, 0].astype(ACCUM_DTYPE)          # (Sq_p, hd_p)
+    q = q_ref[0, 0, 0].astype(ACCUM_DTYPE)          # (tq, hd_p)
     kb = k_ref[0, 0].astype(ACCUM_DTYPE)            # (blk, hd_p)
     s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
                             preferred_element_type=ACCUM_DTYPE) * scale
     if cap is not None:
         s = cap * jnp.tanh(s / cap)
 
-    qp = qpos_ref[0, :].reshape(-1, 1)              # (Sq_p, 1) int32
+    qp = qpos_ref[0]                                # (tq, 1) int32
     kpos = j * blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     valid = kpos < sk                               # padded keys
     if causal:
@@ -88,7 +90,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, qpos_ref, kvlen_ref, o_ref,
     if window is not None:
         valid &= kpos > qp - window
     if has_kvlen:
-        valid &= kpos < kvlen_ref[0, 0]
+        valid &= kpos < kvlen_ref[pl.program_id(0)]
     s = jnp.where(valid, s, NEG_INF)
 
     # Chained row stats over ``chain`` sub-slices of the block: a
@@ -100,10 +102,10 @@ def _attn_kernel(q_ref, k_ref, v_ref, qpos_ref, kvlen_ref, o_ref,
     for lo in range(0, blk, w):
         m_blk = jnp.maximum(
             m_blk, jnp.max(s[:, lo:lo + w], axis=1, keepdims=True))
-    m_old = m_s[...]                                # (Sq_p, LANES)
+    m_old = m_s[...]                                # (tq, LANES)
     m_new = jnp.maximum(m_old, m_blk)
     corr = jnp.exp(m_old - m_new)                   # lane-replicated
-    p = jnp.exp(s - m_new[:, 0:1])                  # (Sq_p, blk)
+    p = jnp.exp(s - m_new[:, 0:1])                  # (tq, blk)
     l_blk = jnp.zeros(l_s.shape, ACCUM_DTYPE)
     for lo in range(0, blk, w):
         sub = p[:, lo:lo + w]
@@ -128,7 +130,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, qpos_ref, kvlen_ref, o_ref,
         p, vb, (((1,), (0,)), ((), ())),
         preferred_element_type=ACCUM_DTYPE)
 
-    @pl.when(j == pl.num_programs(3) - 1)
+    @pl.when(j == pl.num_programs(4) - 1)
     def _finish():
         l = l_s[:, 0:1] - c_s[:, 0:1]
         safe = jnp.where(l > 0.0, l, 1.0)
@@ -147,7 +149,11 @@ def _attn_call(qg, k, v, qpos, kvl, *, causal, window, scale, cap,
     Sk = k.shape[1]
     hd_p = _ceil_to(hd, _LANES)
     hdv_p = _ceil_to(hd_v, _LANES)
-    sq_p = max(_ceil_to(Sq, 8), 8)                  # min f32 sublane tile
+    # Query tile: the whole (8-row padded) Sq up to _Q_TILE rows, else
+    # _Q_TILE-row tiles, so VMEM use does not grow with the sequence.
+    tq = min(max(_ceil_to(Sq, 8), 8), _Q_TILE)
+    sq_p = _ceil_to(Sq, tq)
+    nq = sq_p // tq
     blk = max(_LANES, block_rows)
     sk_p = _ceil_to(Sk, blk)
     nkb = sk_p // blk
@@ -158,8 +164,11 @@ def _attn_call(qg, k, v, qpos, kvl, *, causal, window, scale, cap,
     v_p = jnp.pad(v, ((0, 0), (0, sk_p - Sk), (0, 0),
                       (0, hdv_p - hd_v)))
     # Padded query rows carry position -1: under a causal mask they see
-    # no key at all (sliced off either way).
-    qpos_p = jnp.pad(qpos, ((0, 0), (0, sq_p - Sq)), constant_values=-1)
+    # no key at all (sliced off either way).  Positions ride as a
+    # (B, Sq_p, 1) column so each (tq, 1) block spans the array's last
+    # two dims' tiling; kv_len rides scalar prefetch in SMEM.
+    qpos_p = jnp.pad(qpos, ((0, 0), (0, sq_p - Sq)),
+                     constant_values=-1)[:, :, None]
     q_t = qg_p.transpose(0, 2, 3, 1, 4)             # (B,KV,G,Sq_p,hd_p)
     k_t = k_p.transpose(0, 2, 1, 3)                 # (B,KV,Sk_p,hd_p)
     v_t = v_p.transpose(0, 2, 1, 3)                 # (B,KV,Sk_p,hdv_p)
@@ -167,31 +176,34 @@ def _attn_call(qg, k, v, qpos, kvl, *, causal, window, scale, cap,
     kernel = functools.partial(
         _attn_kernel, blk=blk, chain=int(chain), scale=scale, cap=cap,
         causal=causal, window=window, has_kvlen=has_kvlen, sk=Sk)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, KV, G, nq, nkb),
+        in_specs=[
+            pl.BlockSpec((1, 1, 1, tq, hd_p),
+                         lambda b, h, g, i, j, kvl: (b, h, g, i, 0)),
+            pl.BlockSpec((1, 1, blk, hd_p),
+                         lambda b, h, g, i, j, kvl: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, blk, hdv_p),
+                         lambda b, h, g, i, j, kvl: (b, h, j, 0)),
+            pl.BlockSpec((1, tq, 1),
+                         lambda b, h, g, i, j, kvl: (b, i, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, 1, 1, tq, hdv_p),
+                               lambda b, h, g, i, j, kvl: (b, h, g, i, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((tq, _LANES), ACCUM_DTYPE),     # running max
+            pltpu.VMEM((tq, _LANES), ACCUM_DTYPE),     # normaliser
+            pltpu.VMEM((tq, _LANES), ACCUM_DTYPE),     # Kahan carry
+            pltpu.VMEM((tq, hdv_p), ACCUM_DTYPE),      # value accum
+        ])
     out = pl.pallas_call(
         kernel,
-        grid=(B, KV, G, nkb),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, sq_p, hd_p),
-                         lambda b, h, g, j: (b, h, g, 0, 0)),
-            pl.BlockSpec((1, 1, blk, hd_p),
-                         lambda b, h, g, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, blk, hdv_p),
-                         lambda b, h, g, j: (b, h, j, 0)),
-            pl.BlockSpec((1, sq_p), lambda b, h, g, j: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, g, j: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, sq_p, hdv_p),
-                               lambda b, h, g, j: (b, h, g, 0, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, sq_p, hdv_p),
                                        v.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((sq_p, _LANES), ACCUM_DTYPE),   # running max
-            pltpu.VMEM((sq_p, _LANES), ACCUM_DTYPE),   # normaliser
-            pltpu.VMEM((sq_p, _LANES), ACCUM_DTYPE),   # Kahan carry
-            pltpu.VMEM((sq_p, hdv_p), ACCUM_DTYPE),    # value accum
-        ],
         interpret=interpret,
-    )(q_t, k_t, v_t, qpos_p, kvl[:, None])
+    )(kvl, q_t, k_t, v_t, qpos_p)
     return out.transpose(0, 3, 1, 2, 4)[:, :Sq, :, :, :hd_v]
 
 

@@ -16,11 +16,11 @@ m)`` f32 VMEM tile and:
      TwoSum carry lives in a second scratch buffer), so the
      sequential-grid accumulation stays error-free to first order no
      matter how many tiles stream through;
-  4. on the last step, collapses the ``(split_words, m)`` lane
-     accumulators with a pairwise-TwoSum tree **on the VPU** (not a
-     final MMA — re-rounding the compensated partials through another
-     contraction would throw the carries away) and adds the Kahan
-     carries back in.
+  4. on the last step, folds the ``split_words`` lane accumulators
+     together and collapses the lanes with a TwoSum tree **on the
+     VPU** (not a final MMA — re-rounding the compensated partials
+     through another contraction would throw the carries away), then
+     takes the Kahan carries back out.
 
 All accumulators are f32 (``repro.core.precision.ACCUM_DTYPE``), per
 the paper's single-pass precision contract.
@@ -29,7 +29,7 @@ the paper's single-pass precision contract.
 ``pallas_dd`` engine, kernel sibling of
 ``repro.core.reduction.tc_reduce_dd``): every partial is an
 unevaluated (hi, lo) f32 pair carried via TwoSum/TwoProd, the VMEM
-accumulator holds one compensated f32 row per dd word, and the output
+accumulator holds one compensated f32 plane per dd word, and the output
 is the f64-equivalent ``[hi, lo]`` pair itself (arXiv:2607.06881).
 """
 
@@ -79,25 +79,33 @@ def _two_sum(a, b):
     return s, (a - av) + (b - bv)
 
 
-def _comp_collapse(vals):
-    """Pairwise-TwoSum tree over a (1, k) f32 lane vector -> (1, 1)."""
-    err = jnp.zeros((1, 1), dtype=ACCUM_DTYPE)
+def _comp_collapse(vals, err):
+    """TwoSum tree over a (1, k) f32 lane vector -> (1, 1): each level
+    adds the lower half of the lanes to the upper half (contiguous
+    halves: the TPU has no strided lane slice), an odd lane is folded
+    into a separate tail, and the rounding errors are summed on the
+    side, starting from the (1, 1) correction ``err``.  The result
+    rounds once."""
+    tail = jnp.zeros((1, 1), dtype=ACCUM_DTYPE)
     while vals.shape[-1] > 1:
         k = vals.shape[-1]
         if k % 2:
-            vals = jnp.pad(vals, ((0, 0), (0, 1)))
-            k += 1
-        s, e = _two_sum(vals[:, 0::2], vals[:, 1::2])
+            tail, e = _two_sum(tail, vals[:, k - 1:])
+            err = err + e
+            vals = vals[:, :k - 1]
+            k -= 1
+        s, e = _two_sum(vals[:, :k // 2], vals[:, k // 2:])
         err = err + jnp.sum(e, axis=-1, keepdims=True)
         vals = s
-    return vals + err
+    s, e = _two_sum(vals, tail)
+    return s + (err + e)
 
 
 def mma_ec_kernel(x_ref, o_ref, acc_ref, carry_ref, *, chain: int,
                   block_rows: int, split_words: int,
                   square: bool = False):
     """Compensated split-bf16 reduction: sequential grid, per-word
-    Kahan-compensated (split_words, m) f32 VMEM accumulators."""
+    Kahan-compensated (split_words, 1, m) f32 VMEM accumulators."""
     step = pl.program_id(0)
 
     @pl.when(step == 0)
@@ -110,19 +118,25 @@ def mma_ec_kernel(x_ref, o_ref, acc_ref, carry_ref, *, chain: int,
         tile = tile * tile
     for w, word in enumerate(_split_tile(tile, split_words)):
         contrib = _word_chain(word, chain, block_rows)
-        # Kahan step: carry holds what the last add rounded away.
-        y = contrib - carry_ref[w:w + 1, :]
-        t = acc_ref[w:w + 1, :] + y
-        carry_ref[w:w + 1, :] = (t - acc_ref[w:w + 1, :]) - y
-        acc_ref[w:w + 1, :] = t
+        # Kahan step: carry holds what the last add rounded in excess.
+        acc = acc_ref[w]
+        y = contrib - carry_ref[w]
+        t = acc + y
+        carry_ref[w] = (t - acc) - y
+        acc_ref[w] = t
 
     @pl.when(step == pl.num_programs(0) - 1)
     def _finish():
-        lanes = acc_ref[...].reshape(1, -1)
-        total = _comp_collapse(lanes)
-        # The carries are ~eps * |lanes|: a plain sum of them leaves
-        # only second-order error behind.
-        o_ref[...] = total + jnp.sum(carry_ref[...]).reshape(1, 1)
+        # Fold the words lane-wise with TwoSum, then collapse the lanes.
+        lanes = acc_ref[0]
+        err = -carry_ref[0]
+        for w in range(1, split_words):
+            lanes, e = _two_sum(lanes, acc_ref[w])
+            err = err + e - carry_ref[w]
+        # The errors and carries are ~eps * |lanes|: a plain sum of
+        # them leaves only second-order error behind.
+        o_ref[...] = _comp_collapse(
+            lanes, jnp.sum(err, axis=-1, keepdims=True))
 
 
 def ec_call(x2d, *, chain: int, block_rows: int, split_words: int,
@@ -141,8 +155,8 @@ def ec_call(x2d, *, chain: int, block_rows: int, split_words: int,
         in_specs=[pl.BlockSpec((tile_rows, m), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, 1), ACCUM_DTYPE),
-        scratch_shapes=[pltpu.VMEM((split_words, m), ACCUM_DTYPE),
-                        pltpu.VMEM((split_words, m), ACCUM_DTYPE)],
+        scratch_shapes=[pltpu.VMEM((split_words, 1, m), ACCUM_DTYPE),
+                        pltpu.VMEM((split_words, 1, m), ACCUM_DTYPE)],
         interpret=interpret,
     )(x2d)
 
@@ -173,35 +187,57 @@ def _two_prod(a, b):
     return p, ((ahi * bhi - p) + ahi * blo + alo * bhi) + alo * blo
 
 
-def _dd_pair_level(hi, lo, axis: int):
-    """One halving level of the dd merge tree along ``axis`` (0 or 1).
+def _dd_merge(a, la, b, lb):
+    """dd add of two (hi, lo) planes, elementwise.
 
-    The high-word pair add rounds exactly once — bit-identical to the
+    The high-word add rounds exactly once — bit-identical to the
     pair-granular ones-MMA the core twin
     (``repro.core.reduction.tc_reduce_dd``) routes through
     ``dot_general`` — so the TwoSum residual computed here is exact;
     both low words fold into it and the pair renormalises."""
-    if hi.shape[axis] % 2:
-        pad = ((0, 1), (0, 0)) if axis == 0 else ((0, 0), (0, 1))
-        hi = jnp.pad(hi, pad)
-        lo = jnp.pad(lo, pad)
-    if axis == 0:
-        a, b = hi[0::2, :], hi[1::2, :]
-        la, lb = lo[0::2, :], lo[1::2, :]
-    else:
-        a, b = hi[:, 0::2], hi[:, 1::2]
-        la, lb = lo[:, 0::2], lo[:, 1::2]
     s, e = _two_sum(a, b)
     return _fast_two_sum(s, e + (la + lb))
 
 
+def _dd_collapse(hi, lo, axis: int):
+    """dd merge tree along ``axis`` (0: rows, 1: lanes) down to size 1.
+
+    Halves are contiguous (the TPU has no strided slice).  Rows halve
+    while both halves stay 8-row aligned, the remaining 8-row slabs
+    (or single rows, for a tile that is not a multiple of 8) fold in
+    sequence, and the last slab halves again; lanes halve while even
+    and fold any odd remainder in sequence."""
+    def part(a, lo_i, hi_i):
+        return a[lo_i:hi_i] if axis == 0 else a[:, lo_i:hi_i]
+
+    def halve(h, lw):
+        k = h.shape[axis] // 2
+        return _dd_merge(part(h, 0, k), part(lw, 0, k),
+                         part(h, k, 2 * k), part(lw, k, 2 * k))
+
+    def fold(h, lw, width):
+        ah, al = part(h, 0, width), part(lw, 0, width)
+        for i in range(width, h.shape[axis], width):
+            ah, al = _dd_merge(ah, al, part(h, i, i + width),
+                               part(lw, i, i + width))
+        return ah, al
+
+    if axis == 0:
+        while hi.shape[0] % 16 == 0:
+            hi, lo = halve(hi, lo)
+        hi, lo = fold(hi, lo, 8 if hi.shape[0] % 8 == 0 else 1)
+    while hi.shape[axis] > 1 and hi.shape[axis] % 2 == 0:
+        hi, lo = halve(hi, lo)
+    return fold(hi, lo, 1)
+
+
 def mma_dd_kernel(hi_ref, lo_ref, o_ref, acc_ref, *,
                   square: bool = False):
-    """Double-double reduction: sequential grid, per-word (hi row 0 /
-    lo row 1) TwoSum-compensated ``(2, m)`` f32 VMEM accumulator.
+    """Double-double reduction: sequential grid, per-word (hi plane 0 /
+    lo plane 1) TwoSum-compensated ``(2, 1, m)`` f32 VMEM accumulator.
 
     Each grid step reduces its elementwise-dd tile with a pairwise dd
-    merge tree over rows (see ``_dd_pair_level``) to ``(1, m)`` dd
+    merge tree over rows (see ``_dd_collapse``) to ``(1, m)`` dd
     lanes, then dd-adds them into the persistent accumulator — the
     generalisation of the ``mma_ec`` kernel's Kahan carry to a full
     double word.  The last step collapses the lanes with the same dd
@@ -220,20 +256,15 @@ def mma_dd_kernel(hi_ref, lo_ref, o_ref, acc_ref, *,
         # dd square: (hi + lo)^2 = TwoProd(hi, hi) + 2 hi lo + lo^2.
         p, e = _two_prod(hi, hi)
         hi, lo = _fast_two_sum(p, e + (2.0 * hi * lo + lo * lo))
-    while hi.shape[0] > 1:
-        hi, lo = _dd_pair_level(hi, lo, 0)
+    hi, lo = _dd_collapse(hi, lo, 0)
     # dd_add the tile's (1, m) lanes into the per-word accumulators.
-    s, e = _two_sum(acc_ref[0:1, :], hi)
-    nh, nl = _fast_two_sum(s, e + (acc_ref[1:2, :] + lo))
-    acc_ref[0:1, :] = nh
-    acc_ref[1:2, :] = nl
+    nh, nl = _dd_merge(acc_ref[0], acc_ref[1], hi, lo)
+    acc_ref[0] = nh
+    acc_ref[1] = nl
 
     @pl.when(step == pl.num_programs(0) - 1)
     def _finish():
-        h = acc_ref[0:1, :]
-        low = acc_ref[1:2, :]
-        while h.shape[-1] > 1:
-            h, low = _dd_pair_level(h, low, 1)
+        h, low = _dd_collapse(acc_ref[0], acc_ref[1], 1)
         o_ref[...] = jnp.concatenate([h, low], axis=0)
 
 
@@ -253,6 +284,6 @@ def dd_call(hi2d, lo2d, *, chain: int, block_rows: int,
                   pl.BlockSpec((tile_rows, m), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((2, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((2, 1), ACCUM_DTYPE),
-        scratch_shapes=[pltpu.VMEM((2, m), ACCUM_DTYPE)],
+        scratch_shapes=[pltpu.VMEM((2, 1, m), ACCUM_DTYPE)],
         interpret=interpret,
     )(hi2d, lo2d)

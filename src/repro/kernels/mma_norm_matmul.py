@@ -12,13 +12,16 @@ per-row *scalar*,
 so one kernel pass over the k (feature) axis can accumulate BOTH the
 paper's chained ones-MMA sum of squares AND the unnormalized matmul
 partials, applying the row scaling once at the end — the normalized
-activations never exist in HBM.  Per ``block_rows``-sized k-block (the
-sequential innermost grid axis) the kernel
+activations never exist in HBM.  A grid cell owns a (row tile, output
+tile) pair; per ``block_rows``-sized k-block (the sequential innermost
+grid axis) the kernel
 
   * folds the **row sum of squares** of the raw rows via one
     ``(rows, w) x (w, 128)`` ones-contraction per ``chain`` sub-slice,
     f32 accumulate (``ACCUM_DTYPE``) — exactly the paper's reduction
-    encoding — combined across k-blocks with a Kahan carry in VMEM;
+    encoding — combined across k-blocks with a Kahan carry in VMEM.
+    Only a row tile's first output tile computes it; the later ones
+    reuse it from VMEM;
   * accumulates the **unnormalized matmul partial**
     ``(x * (1 + scale))_blk @ W_blk`` (and the gate projection for the
     MLP up/gate pair) into an f32 VMEM accumulator;
@@ -26,7 +29,8 @@ sequential innermost grid axis) the kernel
 and at the last k-block computes ``rstd = rsqrt(ms / d + eps)``, scales
 the accumulator rows, adds the optional bias, applies the optional
 ``act(gate) * up`` pairing, and writes the output tile — one kernel,
-one read of x, zero intermediate HBM traffic.  This is the fusion shape
+zero intermediate HBM traffic.  Output tiles of at most ``_N_TILE``
+lanes keep VMEM use from growing with ``dout``.  This is the fusion shape
 Dakkak et al. (arXiv:1811.09736) identify: the reduction feeds the
 consuming GEMM without leaving the TCU kernel.
 
@@ -51,6 +55,7 @@ from repro.core.precision import ACCUM_DTYPE
 from repro.kernels.ops import _should_interpret
 
 _LANES = 128     # MXU/VPU lane width: k-blocks and dout pad to it
+_N_TILE = 512    # most output lanes per grid cell
 
 
 def _ceil_to(n: int, m: int) -> int:
@@ -80,37 +85,43 @@ def _nm_kernel(*refs, blk, chain, d, eps, act, has_gate, has_bias):
     acc_s = next(it)
     accg_s = next(it) if has_gate else None
 
-    j = pl.program_id(1)
+    n = pl.program_id(1)
+    j = pl.program_id(2)
+
+    @pl.when((n == 0) & (j == 0))
+    def _init_stat():
+        l_s[...] = jnp.zeros(l_s.shape, ACCUM_DTYPE)
+        c_s[...] = jnp.zeros(c_s.shape, ACCUM_DTYPE)
 
     @pl.when(j == 0)
     def _init():
-        l_s[...] = jnp.zeros(l_s.shape, ACCUM_DTYPE)
-        c_s[...] = jnp.zeros(c_s.shape, ACCUM_DTYPE)
         acc_s[...] = jnp.zeros(acc_s.shape, ACCUM_DTYPE)
         if has_gate:
             accg_s[...] = jnp.zeros(accg_s.shape, ACCUM_DTYPE)
 
     xb = x_ref[...].astype(ACCUM_DTYPE)             # (rt, blk)
 
-    # Chained ones-MMA sum of squares of the RAW rows: one
-    # (rt, w) x (w, 128) ones-contraction per sub-slice, each landing
-    # the sub-slice sum replicated across the 128 output lanes.
-    w = -(-blk // max(chain, 1))
-    l_blk = jnp.zeros(l_s.shape, ACCUM_DTYPE)
-    for lo in range(0, blk, w):
-        sub = xb[:, lo:lo + w]
-        ones = jnp.ones((sub.shape[1], _LANES), ACCUM_DTYPE)
-        l_blk = l_blk + jax.lax.dot_general(
-            sub * sub, ones, (((1,), (0,)), ((), ())),
-            preferred_element_type=ACCUM_DTYPE)
+    @pl.when(n == 0)
+    def _stat():
+        # Chained ones-MMA sum of squares of the RAW rows: one
+        # (rt, w) x (w, 128) ones-contraction per sub-slice, each
+        # landing the sub-slice sum replicated across the 128 lanes.
+        w = -(-blk // max(chain, 1))
+        l_blk = jnp.zeros(l_s.shape, ACCUM_DTYPE)
+        for lo in range(0, blk, w):
+            sub = xb[:, lo:lo + w]
+            ones = jnp.ones((sub.shape[1], _LANES), ACCUM_DTYPE)
+            l_blk = l_blk + jax.lax.dot_general(
+                sub * sub, ones, (((1,), (0,)), ((), ())),
+                preferred_element_type=ACCUM_DTYPE)
 
-    # Kahan carry across k-blocks (the compensated machinery of
-    # kernels/mma_compensated.py, f32 partials per the paper).
-    l_old = l_s[...]
-    y = l_blk - c_s[...]
-    t = l_old + y
-    c_s[...] = (t - l_old) - y
-    l_s[...] = t
+        # Kahan carry across k-blocks (the compensated machinery of
+        # kernels/mma_compensated.py, f32 partials per the paper).
+        l_old = l_s[...]
+        y = l_blk - c_s[...]
+        t = l_old + y
+        c_s[...] = (t - l_old) - y
+        l_s[...] = t
 
     # Unnormalized matmul partial: the gemma (1 + scale) element scale
     # commutes with the matmul, the per-row rstd does not — it is
@@ -125,7 +136,7 @@ def _nm_kernel(*refs, blk, chain, d, eps, act, has_gate, has_bias):
             (((1,), (0,)), ((), ())),
             preferred_element_type=ACCUM_DTYPE)
 
-    @pl.when(j == pl.num_programs(1) - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
         ms = (l_s[:, 0:1] - c_s[:, 0:1]) / d
         rstd = jax.lax.rsqrt(ms + eps)
@@ -148,6 +159,10 @@ def _nm_call(x2d, scale2d, w, *opt, eps, act, has_gate, has_bias,
     d_p = _ceil_to(d, blk)
     nkb = d_p // blk
     dout_p = _ceil_to(dout, _LANES)
+    # Output tile: the widest multiple of 128 lanes, up to _N_TILE,
+    # that divides dout_p.
+    tn = max(t for t in range(_LANES, min(_N_TILE, dout_p) + 1, _LANES)
+             if dout_p % t == 0)
     rt = max(_ceil_to(min(rows, 128), 8), 8)        # row tile
     rows_p = _ceil_to(rows, rt)
 
@@ -155,37 +170,37 @@ def _nm_call(x2d, scale2d, w, *opt, eps, act, has_gate, has_bias,
     s_p = jnp.pad(scale2d, ((0, 0), (0, d_p - d)))
     ops = [x_p, s_p]
     in_specs = [
-        pl.BlockSpec((rt, blk), lambda i, j: (i, j)),
-        pl.BlockSpec((1, blk), lambda i, j: (0, j)),
+        pl.BlockSpec((rt, blk), lambda i, n, j: (i, j)),
+        pl.BlockSpec((1, blk), lambda i, n, j: (0, j)),
     ]
     it = iter(opt)
     for wi in (w, next(it) if has_gate else None):
         if wi is None:
             continue
         ops.append(jnp.pad(wi, ((0, d_p - d), (0, dout_p - dout))))
-        in_specs.append(pl.BlockSpec((blk, dout_p),
-                                     lambda i, j: (j, 0)))
+        in_specs.append(pl.BlockSpec((blk, tn),
+                                     lambda i, n, j: (j, n)))
     if has_bias:
         ops.append(jnp.pad(next(it).reshape(1, dout),
                            ((0, 0), (0, dout_p - dout))))
-        in_specs.append(pl.BlockSpec((1, dout_p), lambda i, j: (0, 0)))
+        in_specs.append(pl.BlockSpec((1, tn), lambda i, n, j: (0, n)))
 
     scratch = [
         pltpu.VMEM((rt, _LANES), ACCUM_DTYPE),      # sum of squares
         pltpu.VMEM((rt, _LANES), ACCUM_DTYPE),      # Kahan carry
-        pltpu.VMEM((rt, dout_p), ACCUM_DTYPE),      # matmul partial
+        pltpu.VMEM((rt, tn), ACCUM_DTYPE),          # matmul partial
     ]
     if has_gate:
-        scratch.append(pltpu.VMEM((rt, dout_p), ACCUM_DTYPE))
+        scratch.append(pltpu.VMEM((rt, tn), ACCUM_DTYPE))
 
     kernel = functools.partial(
         _nm_kernel, blk=blk, chain=int(chain), d=float(d),
         eps=float(eps), act=act, has_gate=has_gate, has_bias=has_bias)
     out = pl.pallas_call(
         kernel,
-        grid=(rows_p // rt, nkb),
+        grid=(rows_p // rt, dout_p // tn, nkb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((rt, dout_p), lambda i, j: (i, 0)),
+        out_specs=pl.BlockSpec((rt, tn), lambda i, n, j: (i, n)),
         out_shape=jax.ShapeDtypeStruct((rows_p, dout_p), x2d.dtype),
         scratch_shapes=scratch,
         interpret=interpret,

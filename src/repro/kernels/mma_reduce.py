@@ -97,9 +97,12 @@ def mma_partials_kernel(x_ref, o_ref, *, chain: int, block_rows: int):
     """One level of the recurrence variant: each grid step reduces its own
     (chain*block_rows, m) tile to a single f32 partial (R+1 MMAs) and
     stores it to its slot — Algorithm 2 of the paper, with the store
-    standing in for ``X[offset / m^2] = C_{0,0}``."""
+    standing in for ``X[offset / m^2] = C_{0,0}``.  The slot is a
+    lane-dense ``(1, 1, m)`` row (the TPU tiles an HBM block's last two
+    dims to (8, 128) unless they span the whole array), holding the
+    partial in every lane."""
     acc = _chain_block(x_ref, chain, block_rows, jnp.float32)
-    o_ref[...] = _collapse(acc, jnp.float32)
+    o_ref[0] = jnp.broadcast_to(_collapse(acc, jnp.float32), acc.shape)
 
 
 def mma_split_kernel(x_ref, o_ref, mma_acc_ref, vpu_acc_ref, *,
@@ -154,7 +157,8 @@ def single_pass_call(x2d, *, chain: int, block_rows: int,
 
 def partials_call(x2d, *, chain: int, block_rows: int,
                   interpret: bool = False):
-    """pallas_call wrapper: (G*chain*block_rows, m) -> (G, 1) f32 partials."""
+    """pallas_call wrapper: (G*chain*block_rows, m) -> (G, 1, m) f32
+    partials, each replicated across its m lanes."""
     rows, m = x2d.shape
     tile_rows = chain * block_rows
     grid = rows // tile_rows
@@ -165,8 +169,8 @@ def partials_call(x2d, *, chain: int, block_rows: int,
         kernel,
         grid=(grid,),
         in_specs=[pl.BlockSpec((tile_rows, m), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((grid, 1), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, m), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((grid, 1, m), jnp.float32),
         interpret=interpret,
     )(x2d)
 

@@ -1,8 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
 These handle flattening, zero-padding to tile boundaries, variant
-dispatch, and interpret-mode selection (the kernels execute in
-interpret=True on CPU so the whole suite validates without a TPU).
+dispatch, and interpret-mode selection: on a TPU the kernels compile
+to Mosaic custom calls; on any other backend (the CPU, where the tests
+run) they run in Pallas interpret mode.
 """
 
 from __future__ import annotations
@@ -102,7 +103,7 @@ def _mma_reduce_impl(x, *, variant: str, chain: int, block_rows: int,
         while x2d.shape[0] > chain * block_rows:
             parts = _mr.partials_call(x2d, chain=chain,
                                       block_rows=block_rows, interpret=itp)
-            x2d = _to_tiles(parts, chain * block_rows, m)
+            x2d = _to_tiles(parts[:, 0, 0], chain * block_rows, m)
         out = _mr.single_pass_call(x2d, chain=chain, block_rows=block_rows,
                                    interpret=itp)
         return out[0, 0]
@@ -234,7 +235,7 @@ def mma_reduce_partials(x, *, chain: int = 4, block_rows: int = 128,
     x2d = _to_tiles(x, chain * block_rows, m)
     parts = _mr.partials_call(x2d, chain=chain, block_rows=block_rows,
                               interpret=itp)
-    return parts[:, 0]
+    return parts[:, 0, 0]
 
 
 def mma_scan(x, *, inclusive: bool = True, chain=4, block_rows=128,
@@ -269,27 +270,15 @@ def _mma_scan_impl(x, *, inclusive: bool, chain: int, block_rows: int,
     return flat.reshape(shape)
 
 
-# VMEM ceiling for the in-kernel one-hot tile of mma_segment_sum: the
-# (block_rows * m, S) f32 mask must stay well under the ~16MB budget
-# alongside the input tile and accumulator.
-_SEG_MASK_BUDGET = 4 * 2**20
-
-
 def mma_segment_sum(values, segment_ids, num_segments: int, *,
                     block_rows=128, m: int = MXU_M,
                     interpret=None) -> jax.Array:
-    """Segmented sum via MMAs against the one-hot segment matrix
-    (Pallas).  ``values``/``segment_ids`` are flattened together;
-    returns (num_segments,) f32.  ``block_rows`` accepts 'auto'
-    (autotuned plan registry, op='segment_sum'); either way it is
-    clamped so the in-kernel (block_rows*m, S) one-hot tile fits VMEM
-    — large segment counts get proportionally shorter tiles."""
+    """Segmented sum via masked ones-MMAs (Pallas).
+    ``values``/``segment_ids`` are flattened together; returns
+    (num_segments,) f32.  ``block_rows`` accepts 'auto' (autotuned
+    plan registry, op='segment_sum')."""
     _, block_rows = _resolve_auto(values, 1, block_rows,
                                   op="segment_sum")
-    s_pad = int(math.ceil(max(int(num_segments), 1) / 128)) * 128
-    max_rows = max(1, _SEG_MASK_BUDGET // (4 * m * s_pad))
-    while block_rows > 1 and block_rows > max_rows:
-        block_rows //= 2
     return _mma_segment_sum_impl(values, segment_ids,
                                  num_segments=int(num_segments),
                                  block_rows=block_rows, m=m,
@@ -302,15 +291,13 @@ def _mma_segment_sum_impl(values, segment_ids, *, num_segments: int,
                           block_rows: int, m: int, interpret) -> jax.Array:
     itp = _should_interpret(interpret)
     v2d = _to_tiles(values, block_rows, m)
-    # Pad ids with -1: padded slots match no segment column.
+    # Pad ids with -1: padded slots match no segment.
     ids = jnp.ravel(segment_ids).astype(jnp.int32)
     pad = v2d.size - ids.shape[0]
     if pad:
         ids = jnp.pad(ids, (0, pad), constant_values=-1)
     ids2d = ids.reshape(v2d.shape)
-    # Lane-align the segment axis; slice the padding off afterwards.
-    s_pad = int(math.ceil(max(num_segments, 1) / 128)) * 128
-    out = _ms.segment_sum_call(v2d, ids2d, num_segments=s_pad,
+    out = _ms.segment_sum_call(v2d, ids2d, num_segments=num_segments,
                                block_rows=block_rows, interpret=itp)
     return out[0, :num_segments]
 

@@ -41,6 +41,7 @@ import numpy as np
 
 from repro.core import integration as ci
 from repro.distributed import sharding as shd
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model_zoo
 from repro.models import transformer as T
 from repro.models.kv_cache import PagedKVCache
@@ -328,6 +329,7 @@ class ContinuousServer:
         self.seed = int(seed)
         self.bucket = bucket
         self._sweeper = None
+        self._sweep_failed_closed = 0
         if background_sweeps:
             from repro.core import autotune
             reg = autotune.default_registry()
@@ -406,6 +408,13 @@ class ContinuousServer:
         return {"plans": len(reg) - before, "scoring_shapes": shapes,
                 "prefill_compiles": len(lens)}
 
+    @property
+    def sweep_failures(self) -> int:
+        """Background sweeps that failed (0 without a worker); counted
+        across ``close()``."""
+        live = 0 if self._sweeper is None else self._sweeper.failed
+        return self._sweep_failed_closed + live
+
     def close(self) -> None:
         """Detach and stop the background sweep worker (idempotent;
         safe with sweeps still in flight — the worker's shutdown
@@ -417,6 +426,7 @@ class ContinuousServer:
         if reg.sweep_worker is self._sweeper:
             reg.sweep_worker = None
         self._sweeper.close()
+        self._sweep_failed_closed += self._sweeper.failed
         self._sweeper = None
 
     def __enter__(self) -> "ContinuousServer":
@@ -593,6 +603,7 @@ def main():
                          "at startup, saved (atomic, file-locked, "
                          "merge-on-save) at exit")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.plan_store:
         from repro.core import autotune

@@ -24,6 +24,7 @@ from repro.data.pipeline import SyntheticLMData
 from repro.distributed import sharding as shd
 from repro.distributed import tc_collectives
 from repro.distributed.fault_tolerance import TrainSupervisor
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model_zoo
 from repro.models.param import axes_tree
 from repro.optim import adamw
@@ -253,6 +254,7 @@ def main():
                          "startup, saved at exit)")
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
+    use_compile_cache()
     run(args.arch, steps=args.steps, smoke=not args.full,
         batch_override=args.batch, seq_override=args.seq,
         microbatches=args.microbatches, ckpt_dir=args.ckpt_dir,

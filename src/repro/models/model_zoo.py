@@ -101,7 +101,9 @@ def build(cfg) -> Model:
     specs = _full_specs(cfg)
 
     def init(key):
-        return init_tree(key, specs)
+        # Weights are drawn in f32 and stored in cfg.param_dtype.
+        return jax.tree_util.tree_map(
+            lambda p: p.astype(cfg.param_dtype), init_tree(key, specs))
 
     def loss(params, batch):
         memory = _memory(params, cfg, batch)

@@ -128,6 +128,8 @@ FUSED_CASES = [
     (33, 160, 2, 16, 16, True, 32, jnp.float32, 2, 256),
     (16, 16, 1, 16, 16, True, None, jnp.bfloat16, 2, 128),
     (33, 160, 2, 16, 16, True, 32, jnp.bfloat16, 2, 128),
+    # several query tiles, the window crossing tile boundaries
+    (1100, 1100, 1, 8, 8, True, 300, jnp.float32, 2, 128),
 ]
 
 

@@ -438,6 +438,25 @@ def test_norm_matmul_capability_predicates(fresh_plan_registry):
                                    err_msg=spelling)
 
 
+def test_norm_matmul_fused_over_several_tiles(fresh_plan_registry):
+    """The fused kernel over two row tiles, three k-blocks and three
+    output tiles (dout 1152 = 3 x 384 lanes) matches the reference:
+    the row statistic the first output tile computes serves the rest."""
+    spec = dispatch.op_spec("norm_matmul")
+    rng = np.random.default_rng(8)
+
+    def t(*shape, s=1.0):
+        return jnp.asarray(s * rng.normal(size=shape).astype(np.float32))
+
+    x = t(200, 300)
+    kw = {"w": t(300, 1152, s=0.05), "scale": t(300, s=0.1),
+          "w_gate": t(300, 1152, s=0.05), "bias": t(1152), "act": "gelu"}
+    got = np.asarray(dispatch.dispatch("norm_matmul", x,
+                                       method="fused_pallas", **kw))
+    want = np.asarray(spec.reference(x, **kw), dtype=np.float64)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
 def test_norm_matmul_auto_error_budget(fresh_plan_registry):
     """method='auto' arbitrates fused-vs-unfused under the policy's
     error budget: a 0.5% budget admits the bf16-multiplicand fused

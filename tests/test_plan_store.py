@@ -20,6 +20,7 @@ trainers), so the acceptance surface here is concurrency-shaped:
 """
 
 import json
+import logging
 import os
 import time
 
@@ -229,6 +230,21 @@ def test_sweep_worker_upgrades_model_plan_off_hot_path(
         # a later identical resolution serves the measured plan
         assert autotune.get_plan(n, jnp.float32,
                                  registry=reg).source == "measured"
+
+
+def test_sweep_worker_counts_and_logs_failures(fresh_plan_registry,
+                                               caplog):
+    """A sweep that raises keeps the model plan serving, but the
+    failure is counted in ``failed`` and logged, never swallowed."""
+    reg = fresh_plan_registry
+    with caplog.at_level(logging.ERROR, logger="repro.core.autotune"):
+        with autotune.SweepWorker(reg, iters=1) as worker:
+            assert worker.submit("no_such_op|512|float32|cpu", dict(
+                n=512, dtype=jnp.float32, op="no_such_op"))
+            assert worker.drain(timeout_s=60.0)
+            assert worker.failed == 1 and worker.upgraded == 0
+    assert any("no_such_op" in r.getMessage() and r.exc_info
+               for r in caplog.records)
 
 
 def test_sweep_worker_dedups_and_close_never_deadlocks(

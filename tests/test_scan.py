@@ -249,8 +249,9 @@ def test_mma_segment_sum_kernel_matches_oracle():
 
 
 def test_mma_segment_sum_clamps_mask_to_vmem():
-    """A large segment count must shrink the row tile (the in-kernel
-    one-hot mask is (block_rows*m, S)) instead of blowing VMEM."""
+    """A large segment count fits VMEM at the default row tile: the
+    kernel holds an (S, m) accumulator, never a (block_rows*m, S)
+    one-hot mask."""
     rng = np.random.default_rng(19)
     s = 4096  # default block_rows=128 would need a 256MB mask tile
     v = jnp.asarray(rng.normal(size=2_000).astype(np.float32))

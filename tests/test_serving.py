@@ -286,5 +286,11 @@ def test_continuous_server_warmup_and_background_sweeps(
             min_new=2, max_new=4, bucket="pow2")]
         got = eng.generate(params, reqs)
         assert sorted(got) == [0, 1, 2]
+        # a sweep that raises is counted, not swallowed
+        assert eng._sweeper.submit("no_such_op|8|float32|cpu", dict(
+            n=8, dtype=jnp.float32, op="no_such_op"))
+        assert eng._sweeper.drain(timeout_s=60.0)
     assert autotune.default_registry().sweep_worker is None
+    # the count survives close() (the worker is gone, the count is not)
+    assert eng.sweep_failures == 1
     eng.close()    # idempotent after context exit

@@ -2,8 +2,11 @@
 the synthetic pipeline, serve it with batched prefill+decode, and resume
 from checkpoint — the full production loop in miniature."""
 
+import os
+
 import jax
 import numpy as np
+import pytest
 
 from repro.launch import train as trainlib
 from repro.launch.serve import Server
@@ -41,3 +44,27 @@ def test_reduction_engine_is_default_everywhere():
     from repro.configs import registry
     for arch in registry.list_archs():
         assert registry.get_config(arch).reduce_method == "mma"
+
+
+@pytest.mark.parametrize("env_set", [False, True])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_set):
+    """A set JAX_COMPILATION_CACHE_DIR is left to JAX (nothing is set);
+    otherwise the cache is pinned to .jax_cache/ at the checkout root."""
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if env_set:
+            d = str(tmp_path / "cache")
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+            assert compile_cache.use_compile_cache() == d
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            got = compile_cache.use_compile_cache()
+            assert got == compile_cache.DEFAULT_DIR
+            assert jax.config.jax_compilation_cache_dir == got
+            assert os.path.basename(got) == ".jax_cache"
+            assert os.path.isfile(os.path.join(
+                compile_cache.CHECKOUT_ROOT, "pyproject.toml"))
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
