@@ -34,10 +34,12 @@ the framework hooks (``repro.core.integration``) call: explicit
 methods are capability-checked (an illegal engine raises ``ValueError``
 with the reason — no hook can silently misroute again), and
 ``method='auto'`` restricts the autotuner's sweep to the engines that
-are *legal for this call* before executing the winning plan through
-``execute``.  The autotuner (``repro.core.autotune``) enumerates its
-candidate space off the same registry, so adding an op or an engine is
-one ``register()`` call — not another dispatch ladder.
+are *legal for this call* before executing the winning plan.  Both
+paths are routed by ``_route`` and run the engine in one place, where
+the call is counted and traced (``repro.obs``).  The autotuner
+(``repro.core.autotune``) enumerates its candidate space off the same
+registry, so adding an op or an engine is one ``register()`` call —
+not another dispatch ladder.
 
 This module is deliberately the only place in ``src/`` where engine
 names are compared (``scripts/check.sh`` greps for ``method ==``
@@ -53,6 +55,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.precision import MmaPolicy, as_policy
 
 # ------------------------------------------------------------- context
@@ -419,6 +422,7 @@ def resolve_method(op: str, x, method: str, *, fallback: str = "vpu",
             f"predicates"
             + (f" under precision policy {pol.signature()!r}"
                if pol is not None else ""))
+    obs.count("dispatch.fallbacks", (op, method, fallback))
     return fallback
 
 
@@ -462,7 +466,39 @@ def dispatch(op: str, x, *, method: str = "auto", chain=None,
     ``'geom'`` for the paper-geometry m²-aligned caps; ``None`` opts
     out to exact-n keys).  One plan tuned at the bucket cap serves
     every shape in the bucket; explicit methods ignore it.
+
+    An eager call counts once in ``dispatch.calls`` and
+    ``dispatch.bytes`` under ``(op, engine)``, the engine that served
+    it, and while a JAX profile records it is span ``repro.dispatch``
+    (the registry's routing and the engine run) with child span
+    ``repro.engine`` (the engine run; ``repro.obs``).  Under a jit
+    trace a host span would time tracing and a count would count
+    traces, so neither is recorded there: the engine runs inside
+    ``jax.named_scope("<op>.<engine>")`` instead, so the compiled ops
+    say which registry op they came from.
     """
+    if isinstance(x, jax.core.Tracer):
+        eng, x, plan, op_kwargs = _route(op, x, method, chain, precision,
+                                         objective, bucket, op_kwargs)
+        with jax.named_scope(f"{op}.{eng.name}"):
+            return eng.run(x, plan, **op_kwargs)
+    with obs.span("repro.dispatch") as sp:
+        eng, x, plan, op_kwargs = _route(op, x, method, chain, precision,
+                                         objective, bucket, op_kwargs)
+        key, nbytes = (op, eng.name), int(x.size) * x.dtype.itemsize
+        obs.count("dispatch.calls", key)
+        obs.count("dispatch.bytes", key, nbytes)
+        sp.set(op=op, method=method, engine=eng.name, n=x.size,
+               bytes=nbytes)
+        with obs.span("repro.engine", engine=eng.name):
+            return eng.run(x, plan, **op_kwargs)
+
+
+def _route(op: str, x, method: str, chain, precision, objective,
+           bucket, op_kwargs: dict) -> tuple:
+    """The registry's decision for one ``dispatch`` call:
+    ``(engine, input, plan, op_kwargs)``, with the input cast as the
+    policy asks and the engine checked against it."""
     from repro.core import autotune
     spec = op_spec(op)
     policy = as_policy(precision)
@@ -487,8 +523,9 @@ def dispatch(op: str, x, *, method: str = "auto", chain=None,
                                  x.dtype, op=op, engine=restrict,
                                  mesh=ctx.mesh_axes, policy=policy,
                                  objective=objective, bucket=bucket)
-        return execute(op, _cast_in(x, policy, spec, plan.method),
-                       plan, **op_kwargs)
+        x = _cast_in(x, policy, spec, plan.method)
+        eng = _plan_engine(spec, x, plan, op_kwargs)
+        return eng, x, plan, op_kwargs
     eng = spec.engine(method)
     if eng is None:
         raise _unknown_method(spec, method)
@@ -502,11 +539,12 @@ def dispatch(op: str, x, *, method: str = "auto", chain=None,
                                  x.dtype, op=op, engine=(eng.name,),
                                  mesh=ctx.mesh_axes, policy=policy,
                                  objective=objective, bucket=bucket)
-        return execute(op, x, plan, **op_kwargs)
+        eng = _plan_engine(spec, x, plan, op_kwargs)
+        return eng, x, plan, op_kwargs
     overrides = {} if chain is None else {"chain": int(chain)}
     overrides.update(_plan_words(policy))
     plan = autotune.ReductionPlan(method=eng.name, **overrides)
-    return eng.run(x, plan, **op_kwargs)
+    return eng, x, plan, op_kwargs
 
 
 def _cast_in(x, policy: Optional[MmaPolicy], spec: "OpSpec",
@@ -528,22 +566,31 @@ def _cast_in(x, policy: Optional[MmaPolicy], spec: "OpSpec",
 def execute(op: str, x, plan, **op_kwargs):
     """Run ``x`` under an already-chosen plan — the single executor.
 
-    The auto path, the autotuner's measured sweep, and the benchmark
-    drivers all land here.  The plan's engine is validated against the
-    op's structural capabilities (axis/layout/ndim — not the mesh, so
-    candidate plans can be timed on a single host).
+    The autotuner's measured sweep, the mesh collectives and the
+    benchmark drivers land here.  The plan's engine is validated
+    against the op's structural capabilities (axis/layout/ndim — not
+    the mesh, so candidate plans can be timed on a single host).  It
+    counts and traces nothing: a sweep's candidate runs are not calls
+    the registry served.
     """
     spec = op_spec(op)
+    return _plan_engine(spec, x, plan, op_kwargs).run(x, plan, **op_kwargs)
+
+
+def _plan_engine(spec: OpSpec, x, plan, op_kwargs: dict) -> EngineSpec:
+    """The plan's engine, checked against the op's structural
+    capabilities for ``x``."""
     eng = spec.engine(plan.method)
     if eng is None:
         raise ValueError(f"unknown plan method {plan.method!r} for op "
-                         f"{op!r} (engines: {spec.engine_names()})")
+                         f"{spec.name!r} (engines: "
+                         f"{spec.engine_names()})")
     reason = capability_reason(eng, _context_for(spec, x, op_kwargs),
                                env=False)
     if reason is not None:
-        raise ValueError(
-            f"engine {eng.name!r} cannot run op {op!r} here: {reason}")
-    return eng.run(x, plan, **op_kwargs)
+        raise ValueError(f"engine {eng.name!r} cannot run op "
+                         f"{spec.name!r} here: {reason}")
+    return eng
 
 
 def _context_for(spec: OpSpec, x, op_kwargs: dict, *,
