@@ -668,9 +668,7 @@ def _f32(x):
 
 def _reduce_mma(x, plan, *, axis=None, **_):
     from repro.core import reduction as R
-    if axis is None:
-        return R.tc_contract(x, jnp.ones_like(x))
-    return R.tc_reduce_axes(x, axis)
+    return R.tc_sum(x, axis)
 
 
 def _reduce_chained(x, plan, **_):
@@ -703,9 +701,7 @@ def _reduce_pallas_ec(x, plan, **_):
 
 def _sq_mma(x, plan, *, axis=None, **_):
     from repro.core import reduction as R
-    if axis is None:
-        return R.tc_contract(x, x)
-    return R.tc_reduce_axes(x, axis, b=x)
+    return R.tc_squared_sum(x, axis)
 
 
 def _sq_chained(x, plan, **_):

@@ -302,6 +302,38 @@ def tc_reduce_axes(x, axes: tuple, *, b=None) -> jax.Array:
         preferred_element_type=ACCUM_DTYPE)
 
 
+def tc_sum(x, axes=None) -> jax.Array:
+    """Sum of ``x`` over ``axes`` (None = every element), f32: the
+    ones-contraction as one compiled program that reads ``x`` once.
+
+    The ones are made inside the program, so XLA fuses them into the
+    contraction; an eager ``tc_contract(x, ones_like(x))`` first writes
+    a whole array of ones to memory and then reads it back beside ``x``.
+    ``axes`` is a sorted tuple of non-negative ints (``tc_reduce_axes``).
+    Under an outer ``jit`` the program is inlined.
+    """
+    return _contraction(x, axes=axes, square=False, contract=tc_contract)
+
+
+def tc_squared_sum(x, axes=None) -> jax.Array:
+    """Sum of ``x * x`` over ``axes`` (None = every element), f32: the
+    contraction of ``x`` with itself as one compiled program whose only
+    parameter is ``x``.  An eager ``tc_contract(x, x)`` is a program of
+    two parameters, which loads the same buffer twice.
+    """
+    return _contraction(x, axes=axes, square=True, contract=tc_contract)
+
+
+@functools.partial(jax.jit, static_argnames=("axes", "square", "contract"))
+def _contraction(x, *, axes, square: bool, contract):
+    # ``contract`` is static so the program is keyed on the contraction
+    # it was traced with: a ``tc_contract`` replaced at run time gets a
+    # program of its own, not the one cached for the function it replaced.
+    if axes is None:
+        return contract(x, x if square else jnp.ones_like(x))
+    return tc_reduce_axes(x, axes, b=x if square else None)
+
+
 @jax.jit
 def tc_reduce_lastdim(x) -> jax.Array:
     """Ones-contraction over the last dim: (..., d) -> (...) f32 sums.

@@ -20,6 +20,11 @@ automatically widens this suite.
     family through the registry runners.
 """
 
+import json
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -546,3 +551,148 @@ def test_auto_path_restricts_to_legal_engines(fresh_plan_registry):
     restricted = [k for k in keys if k.startswith("reduce_sum")
                   and k.endswith("|mma+vpu")]
     assert restricted, keys
+
+
+# ------------------------------- the eager mma engine: one program
+
+
+MMA_HOOKS = {"reduce_sum": ci.reduce_sum, "squared_sum": ci.squared_sum}
+MMA_INPUTS = {                     # name -> (shape, axis)
+    "1d": ((4_111,), None),
+    "3d_all": ((5, 9, 13), None),
+    "3d_subset": ((5, 9, 13), (0, 2)),
+}
+_MMA_CASES = [(op, case) for op in MMA_HOOKS for case in MMA_INPUTS]
+
+
+def _mma_input(case):
+    shape, axis = MMA_INPUTS[case]
+    rng = np.random.default_rng(sum(shape))
+    return jnp.asarray(rng.normal(size=shape).astype(np.float32)), axis
+
+
+def _mma_program(op, x, axis):
+    """The program the ``mma`` runner of ``op`` compiles for ``x``."""
+    from repro.core import reduction as R
+    return R._contraction.lower(x, axes=axis, square=op == "squared_sum",
+                                contract=R.tc_contract)
+
+
+@pytest.fixture()
+def compiled_programs():
+    """Names of the programs JAX compiles while the test runs."""
+    from jax import monitoring
+    from jax._src import dispatch as jax_dispatch
+    seen = []
+
+    def on(event, secs, **kw):
+        if event == jax_dispatch.BACKEND_COMPILE_EVENT:
+            seen.append(kw.get("fun_name", "?"))
+
+    monitoring.register_event_duration_secs_listener(on)
+    try:
+        yield seen
+    finally:
+        monitoring.unregister_event_duration_listener(on)
+
+
+@pytest.mark.parametrize("op,case", _MMA_CASES)
+def test_eager_mma_is_one_program(op, case, compiled_programs):
+    """An eager ``mma`` call compiles one program, no ``ones_like``
+    beside it, and runs that program again on the next call."""
+    x, axis = _mma_input(case)
+    jax.clear_caches()
+    dispatch.dispatch(op, x, method="mma", axis=axis).block_until_ready()
+    assert compiled_programs == ["jit(_contraction)"], compiled_programs
+    dispatch.dispatch(op, x, method="mma", axis=axis).block_until_ready()
+    assert compiled_programs == ["jit(_contraction)"], compiled_programs
+
+
+@pytest.mark.parametrize("op,case", _MMA_CASES)
+def test_eager_mma_program_reads_only_x_in_f32(op, case):
+    """The program's only parameter is ``x`` (no ones operand), it has
+    no bf16 anywhere, and its contraction returns f32."""
+    x, axis = _mma_input(case)
+    low = _mma_program(op, x, axis)
+    text = low.as_text()
+    main = next(l for l in text.splitlines() if "func.func public @main" in l)
+    assert main.count("%arg") == 1, main
+    assert "bf16" not in text
+    dots = [l for l in text.splitlines() if "stablehlo.dot_general" in l]
+    assert len(dots) == 1, dots
+    assert dots[0].rstrip().endswith("f32>"), dots[0]
+    hlo = low.compile().as_text()
+    entry = hlo.split("ENTRY")[1]
+    assert entry.count("parameter(") == 1, entry
+    assert "bf16" not in hlo
+
+
+@pytest.mark.parametrize("op,case", _MMA_CASES)
+def test_eager_mma_matches_float64_and_vpu(op, case):
+    x, axis = _mma_input(case)
+    x64 = np.asarray(x, np.float64)
+    want = np.sum(x64 * x64 if op == "squared_sum" else x64, axis=axis)
+    got = dispatch.dispatch(op, x, method="mma", axis=axis)
+    assert got.dtype == jnp.float32 and got.shape == np.shape(want)
+    tol = dict(rtol=1e-5, atol=1e-5 * np.sqrt(x.size))
+    np.testing.assert_allclose(np.asarray(got, np.float64), want, **tol)
+    vpu = dispatch.dispatch(op, x, method="vpu", axis=axis)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(vpu), **tol)
+
+
+@pytest.mark.parametrize("op", MMA_HOOKS)
+def test_mma_under_outer_jit_is_one_contraction_of_x(op):
+    """Inside a caller's jit the runner's program is inlined: one
+    contraction, the ones (if any) made from a scalar in the program,
+    and ``x`` the only parameter."""
+    x, _ = _mma_input("3d_all")
+    low = jax.jit(lambda v: MMA_HOOKS[op](v, method="mma")).lower(x)
+    text = low.as_text()
+    assert text.count("stablehlo.dot_general") == 1, text
+    main = next(l for l in text.splitlines() if "func.func public @main" in l)
+    assert main.count("%arg") == 1, main
+    ones = [l for l in text.splitlines() if "dense<1.000000e+00>" in l]
+    assert all(l.rstrip().endswith("tensor<f32>") for l in ones), ones
+    assert low.compile().as_text().split("ENTRY")[1].count(
+        "parameter(") == 1
+
+
+_SHARDED_PROG = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from repro.core import integration as ci, reduction as R
+
+mesh = Mesh(np.array(jax.devices()[:4]), ("d",))
+xh = np.random.default_rng(0).normal(size=4096).astype(np.float32)
+x = jax.device_put(jnp.asarray(xh), NamedSharding(mesh, P("d")))
+out = {}
+for op, sq in (("reduce_sum", False), ("squared_sum", True)):
+    got = float(getattr(ci, op)(x, method="mma"))
+    hlo = R._contraction.lower(x, axes=None, square=sq,
+                               contract=R.tc_contract).compile().as_text()
+    x64 = xh.astype(np.float64)
+    out[op] = {"got": got, "want": float(np.sum(x64 * x64 if sq else x64)),
+               "all_reduce": hlo.count(" all-reduce("),
+               "local": "f32[1024]" in hlo, "global": "f32[4096]" in hlo}
+print("RESULT" + json.dumps(out))
+"""
+
+
+def test_eager_mma_of_a_sharded_input_is_local_plus_one_all_reduce():
+    """On a 4-device mesh the eager program contracts each shard where
+    it lies and combines the partial sums with one all-reduce."""
+    env = dict(os.environ, PYTHONPATH=os.path.join(
+        os.path.dirname(__file__), "..", "src"))
+    p = subprocess.run([sys.executable, "-c", _SHARDED_PROG],
+                       capture_output=True, text=True, env=env,
+                       timeout=300)
+    assert p.returncode == 0, p.stderr[-3000:]
+    line = [l for l in p.stdout.splitlines() if l.startswith("RESULT")][0]
+    for op, r in json.loads(line[len("RESULT"):]).items():
+        assert r["all_reduce"] == 1, (op, r)
+        assert r["local"] and not r["global"], (op, r)
+        np.testing.assert_allclose(r["got"], r["want"], rtol=1e-5,
+                                   err_msg=op)
