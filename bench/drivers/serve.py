@@ -7,7 +7,13 @@ cached decode step, the final norm and the logits, greedy picks.  The
 weights are the seeded checkpoint of ``bench.checkpoint``, loaded into
 the program's layout on the device in one jitted call.  The window
 drives ``serve`` over the mix's offline queue, which never drains, and
-stamps each token as the host receives it.
+stamps each token as the host receives it; it closes with the first
+token that comes at or past its length, and that token's work, an
+admission's whole prefill perhaps, is counted in it as its time is.
+After the window closes
+the same server goes on, untimed, until the requests it holds have
+finished enough tokens for the check (at most ``DRAIN_S``): a long
+answer that was in flight at the close is late, not missing.
 """
 
 from __future__ import annotations
@@ -23,6 +29,8 @@ import numpy as np
 from bench import checkpoint as C
 from bench import flops, generator, stats
 from bench import weights as W
+
+DRAIN_S = 60.0
 
 
 def model_config(cfg: dict):
@@ -129,10 +137,12 @@ class Record:
     t_close: float
     tokens: dict          # uid -> [token]
     times: dict           # uid -> [perf_counter s]
-    done: set             # uids whose last token came in the window
     prompts: dict         # uid -> prompt (np.int32)
     spans: list           # (name, t0, t1)
     dec: flops.Decoder
+    events: list          # (uid, index) of each token, in arrival order
+    served: dict          # uid -> [token], the window's and the drain's
+    finished: set         # uids whose last token came by the drain's end
 
     @property
     def window_s(self) -> float:
@@ -159,7 +169,8 @@ def setup(cfg: dict, mix: dict, seed: int) -> State:
 
 
 def window(state: State, seconds: float) -> Record:
-    tokens, times, done, spans = {}, {}, set(), []
+    tokens, times, spans, events = {}, {}, [], []
+    finished = set()
     prompts = {r["uid"]: r["prompt"] for r in state.queue}
     gen = state.server.serve(state.params, state.queue)
     t_open = time.perf_counter()
@@ -169,21 +180,40 @@ def window(state: State, seconds: float) -> Record:
             t0 = time.perf_counter()
             ev = next(gen)
             t1 = time.perf_counter()
-            if t1 >= t_end:
-                break
             tokens.setdefault(ev.uid, []).append(ev.token)
             times.setdefault(ev.uid, []).append(t1)
+            events.append((ev.uid, ev.index))
             if ev.done:
-                done.add(ev.uid)
+                finished.add(ev.uid)
             spans.append(("bench.admit" if ev.index == 0
                           else "bench.decode", t0, t1))
+            if t1 >= t_end:
+                break
+        served = {u: list(t) for u, t in tokens.items()}
+        _drain(gen, served, finished, state.mix["check"], t1)
     except StopIteration:
         raise RuntimeError("the queue drained inside the window: the "
                            "mix needs more rounds") from None
     finally:
         gen.close()
-    return Record(t_open, t1, tokens, times, done,
-                  {u: prompts[u] for u in tokens}, spans, state.dec)
+    return Record(t_open, t1, tokens, times,
+                  {u: prompts[u] for u in served}, spans, state.dec,
+                  events, served, finished)
+
+
+def _drain(gen, served, finished, want, t_close) -> None:
+    """Serve on past the close until the finished requests fill the
+    check's sample (its count or its tokens, as ``sample`` stops at
+    either), or ``DRAIN_S`` has passed."""
+    def enough():
+        return len(finished) >= want["max_requests"] or \
+            sum(len(served[u]) for u in finished) >= want["tokens"]
+
+    while not enough() and time.perf_counter() < t_close + DRAIN_S:
+        ev = next(gen)
+        served.setdefault(ev.uid, []).append(ev.token)
+        if ev.done:
+            finished.add(ev.uid)
 
 
 def end_to_end(rec: Record) -> dict:
@@ -191,23 +221,48 @@ def end_to_end(rec: Record) -> dict:
     gaps = stats.intertoken_gaps(rec.times)
     return {
         "output_tok_s": sum(map(len, rec.tokens.values())) / w,
-        "prompt_tok_s": sum(len(p) for p in rec.prompts.values()) / w,
+        "prompt_tok_s": sum(len(rec.prompts[u]) for u in rec.tokens) / w,
         "itl_p95_ms": stats.percentile(gaps, 95) * 1e3,
     }
 
 
+def decode_steps(rec: Record) -> list:
+    """The window's batched decode steps, each as the context (earlier
+    positions) of every slot it decoded.  ``serve`` yields a step's
+    admissions first, then one token per live slot; so a step begins
+    at the first decode token after an admission, or where a request
+    decodes a second time."""
+    steps, uids, fresh = [], set(), True
+    for uid, i in rec.events:
+        if i == 0:
+            fresh = True
+            continue
+        if fresh or uid in uids:
+            steps.append([])
+            uids, fresh = set(), False
+        uids.add(uid)
+        steps[-1].append(len(rec.prompts[uid]) + i - 1)
+    return steps
+
+
 def work(rec: Record) -> dict:
     """Model FLOPs the window's tokens needed (prefills of its
-    admissions, decode of every later token) and its prompt tokens."""
+    admissions, decode of every later token), its prompt tokens, and
+    the bytes and FLOPs its decode steps needed."""
     dec = rec.dec
     fl = 0
     for uid, toks in rec.tokens.items():
         n = len(rec.prompts[uid])
         fl += dec.prefill_flops(n)
         fl += sum(dec.decode_flops(n + i - 1) for i in range(1, len(toks)))
+    steps = decode_steps(rec)
     return {"flops": fl,
-            "prompt_tokens": sum(len(p) for p in rec.prompts.values()),
-            "output_tokens": sum(map(len, rec.tokens.values()))}
+            "prompt_tokens": sum(len(rec.prompts[u]) for u in rec.tokens),
+            "output_tokens": sum(map(len, rec.tokens.values())),
+            "decode_steps": len(steps),
+            "decode_bytes": sum(dec.decode_step_bytes(c) for c in steps),
+            "decode_flops": sum(dec.decode_flops(n) for c in steps
+                                for n in c)}
 
 
 def release(state: State) -> None:
@@ -220,7 +275,7 @@ def sample(rec: Record, mix: dict, seed: int) -> list:
     """Finished requests to check: the one with the most served tokens,
     then others in the seed's order, up to the mix's ``check`` sizes."""
     want = mix["check"]
-    done = sorted(rec.done, key=lambda u: (-len(rec.tokens[u]), u))
+    done = sorted(rec.finished, key=lambda u: (-len(rec.served[u]), u))
     if not done:
         return []
     rest = done[1:]
@@ -233,7 +288,7 @@ def sample(rec: Record, mix: dict, seed: int) -> list:
         if len(out) >= want["max_requests"] or served >= want["tokens"]:
             break
         out.append(uid)
-        served += len(rec.tokens[uid])
+        served += len(rec.served[uid])
     return out
 
 
@@ -246,7 +301,7 @@ def check(rec: Record, cfg: dict, mix: dict, seed: int, ref,
     if not uids:
         return {"finished_requests": (0, 1)}, 1
     seqs = [(np.concatenate([rec.prompts[u],
-                             np.asarray(rec.tokens[u], np.int32)]),
+                             np.asarray(rec.served[u], np.int32)]),
              len(rec.prompts[u])) for u in uids]
     gaps = ref.served_gaps(cfg, seed, seqs)
     worst = [float(np.max(g)) for g in gaps]
@@ -268,7 +323,7 @@ def readings(cfg: dict, mix: dict, seed: int, seconds: float,
     if not uids:
         return {"answers": 0, "program": {}, "control": {}}
     seqs = [(np.concatenate([rec.prompts[u],
-                             np.asarray(rec.tokens[u], np.int32)]),
+                             np.asarray(rec.served[u], np.int32)]),
              len(rec.prompts[u])) for u in uids]
     prog = ref.served_gaps(cfg, seed, seqs)
     ctrl = ref.served_gaps(cfg, seed, seqs, control=True)

@@ -60,6 +60,21 @@ class Decoder:
                            + self.embed_params() + self.head_params()
                            + self.d)
 
+    def kv_bytes_per_token(self, itemsize: int = 2) -> int:
+        """K and V of one position, over every layer."""
+        return 2 * self.kv * self.hd * self.layers * itemsize
+
+    def decode_step_bytes(self, contexts, itemsize: int = 2) -> int:
+        """Bytes one batched decode step has to read: every layer's
+        weights and the output layer's once, and the K and V of each
+        live slot's earlier positions (``contexts``, one per slot).
+        The embedding row and the slots' dead capacity are not
+        needed."""
+        keys = int(np.sum(np.asarray(contexts, np.int64)))
+        return (itemsize * (self.layers * self.layer_params()
+                            + self.head_params())
+                + self.kv_bytes_per_token(itemsize) * keys)
+
     def attention_flops(self, query_positions) -> int:
         """Scores and the weighted sum of values: 4 * heads * head_dim
         multiply-adds per (query, key) pair, per layer; a query at

@@ -1,11 +1,12 @@
 """The one traffic generator: it reads a mix's parameters from
 ``bench/traffic/<mix>.json`` and makes the mix's work from the seed.
 
-Every seed gets the same set of sizes, only in another order: the mix
-is a queue of *rounds*, each holding the mix's exact proportions, and
-the seed shuffles each round and draws the payloads (prompt tokens).
-So runs with different seeds do the same work, and a window that ends
-anywhere has seen the mix's proportions up to one round.
+Every seed gets the same work: the mix is a queue of *rounds*, each
+holding the mix's exact proportions.  A ``calls`` round is shuffled by
+the seed; a ``requests`` queue takes its sizes in one fixed order for
+every seed, and the seed draws only the payloads (prompt tokens), since
+a window of a few dozen requests ends inside a round, and which sizes
+it then holds would otherwise change with the seed.
 
 Two kinds of mix:
 
@@ -15,7 +16,7 @@ Two kinds of mix:
   ``round`` requests whose prompt lengths follow ``prompt_weights``
   over ``prompt_buckets`` exactly, and whose ``max_new`` sit at the
   round's evenly spaced quantiles of the ``max_new`` distribution,
-  paired with the lengths one fixed way.
+  paired with the lengths and ordered one fixed way.
 
 Adapted from ``repro.data.pipeline.synthetic_requests`` (seeded and
 counter based, bucketed lengths), with the distributions of users'
@@ -111,14 +112,14 @@ def requests(mix: dict, seed: int, vocab: int, *, rounds=None,
         raise ValueError("only greedy traffic can be checked token by "
                          "token against the reference")
     lens, budgets = _round_lengths(mix), _round_budgets(mix)
-    # One fixed pairing of lengths with budgets for every seed; the
-    # seed only orders the pairs and draws the tokens.
+    # One fixed pairing of lengths with budgets, and one fixed order of
+    # each round, for every seed; the seed only draws the tokens.
     pairs = [(lens[i], budgets[j]) for i, j in enumerate(
         np.random.default_rng(0).permutation(len(budgets)))]
     rounds = int(mix["rounds"] if rounds is None else rounds)
     out = []
     for r in range(rounds):
-        for k in _rng(seed, 2, r).permutation(len(pairs)):
+        for k in _rng(0, 2, r).permutation(len(pairs)):
             length, budget = pairs[k]
             uid = start_uid + len(out)
             out.append({

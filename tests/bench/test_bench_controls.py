@@ -20,12 +20,14 @@ caught by ``reduce_sum``; and the decoder at a width of
 0.0079, control 0.046 to 0.074 over four seeds).
 """
 
+import glob
 import json
 import os
 
 import numpy as np
+import pytest
 
-from bench import run
+from bench import generator, run
 
 SMALL_GAP_LIMIT = 0.02
 CPU_SUM_LIMIT = 1e-4
@@ -82,9 +84,12 @@ def _run(config, traffic, cfg, mix, seconds=4.0):
                           "traffic": traffic, "chips": 1, "why": "test"}]
     spec["configs"] = [{"name": config,
                         "file": f"bench/configs/{config}.json"}]
-    # The metrics of BENCHMARK.json's cells of this config and traffic.
+    # The metrics of BENCHMARK.json's cells of this config and traffic,
+    # or of this config where no cell runs the traffic yet.
     cells = {c["name"] for c in run.load_spec()["workloads"]
-             if (c["config"], c["traffic"]) == (config, traffic)}
+             if (c["config"], c["traffic"]) == (config, traffic)} or \
+        {c["name"] for c in run.load_spec()["workloads"]
+         if c["config"] == config}
     spec["end_to_end"] = [dict(m, workloads=["t.cell"])
                           for m in spec["end_to_end"]
                           if cells & set(m.get("workloads", cells))]
@@ -130,8 +135,18 @@ def test_decoder_control_reads_above_the_limit_and_program_below():
         < r["control"]["max_logit_gap"]
 
 
-def test_serve_run_is_correct_and_an_altered_token_is_not(monkeypatch):
-    out = _run("glm4-9b", "decode-heavy", small_decoder(), SERVE_MIX)
+REQUEST_MIXES = sorted(
+    n for n in (os.path.basename(p)[:-len(".json")] for p in glob.glob(
+        os.path.join(run.BENCH, "traffic", "*.json")))
+    if generator.load_mix(n)["kind"] == "requests")
+
+
+@pytest.mark.parametrize("traffic", REQUEST_MIXES)
+def test_serve_run_is_correct_and_an_altered_token_is_not(monkeypatch,
+                                                          traffic):
+    # The small mix with as many slots in use as the traffic's own.
+    mix = dict(SERVE_MIX, slots=generator.load_mix(traffic)["slots"])
+    out = _run("glm4-9b", traffic, small_decoder(), mix)
     assert out["correct"], out["checks"]
     from repro.launch import serve
     real = serve.ContinuousServer._pick
@@ -141,5 +156,62 @@ def test_serve_run_is_correct_and_an_altered_token_is_not(monkeypatch):
         return (tok + 1) % self.cfg.vocab_size if index == 3 else tok
 
     monkeypatch.setattr(serve.ContinuousServer, "_pick", altered)
-    out = _run("glm4-9b", "decode-heavy", small_decoder(), SERVE_MIX)
+    out = _run("glm4-9b", traffic, small_decoder(), mix)
     assert not out["correct"] and out["failed"] > 0
+
+
+def test_a_request_in_flight_at_the_close_is_served_to_its_end():
+    import time
+    from types import SimpleNamespace as Ev
+    driver = run.load_module(os.path.join(run.BENCH, "drivers",
+                                          "serve.py"), "t_drv_drain")
+    # Request 1 had tokens 0-1 in the window; 2-4 come after the close.
+    after = [Ev(uid=1, index=i, token=10 + i, done=i == 4)
+             for i in (2, 3, 4)] + [Ev(uid=2, index=0, token=7,
+                                       done=False)] * 5
+    want = {"max_requests": 1, "tokens": 5}
+    served, finished = {1: [10, 11]}, set()
+    gen = iter(after)
+    driver._drain(gen, served, finished, want, time.perf_counter())
+    assert finished == {1} and served[1] == [10, 11, 12, 13, 14]
+    assert next(gen).uid == 2          # nothing served past the need
+    # Past its time the drain serves nothing more.
+    served, finished = {1: [10, 11]}, set()
+    driver._drain(iter(after), served, finished, want,
+                  time.perf_counter() - driver.DRAIN_S)
+    assert served == {1: [10, 11]} and not finished
+
+
+def test_the_token_that_closes_the_window_counts_in_it():
+    import time
+    from types import SimpleNamespace as Ev
+    driver = run.load_module(os.path.join(run.BENCH, "drivers",
+                                          "serve.py"), "t_drv_close")
+
+    class Server:
+        def serve(self, params, queue):
+            # Request 1 admitted and decoded once, then request 2,
+            # whose admission (a long prefill) straddles the window's
+            # length; request 1 ends after the close.
+            for ev, wait in ((Ev(uid=1, index=0, token=5, done=False), 0),
+                             (Ev(uid=1, index=1, token=7, done=False), 0),
+                             (Ev(uid=2, index=0, token=6, done=False),
+                              0.2),
+                             (Ev(uid=1, index=2, token=9, done=True), 0)):
+                time.sleep(wait)
+                yield ev
+            while True:
+                yield Ev(uid=3, index=0, token=8, done=True)
+
+    queue = [{"uid": u, "prompt": np.zeros(n, np.int32)}
+             for u, n in ((1, 10), (2, 40), (3, 1))]
+    state = driver.State(cfg={}, mix={"check": {"max_requests": 1,
+                                                "tokens": 2}},
+                         seed=0, dec=None, server=Server(), params=None,
+                         queue=queue)
+    rec = driver.window(state, 0.1)
+    assert rec.tokens == {1: [5, 7], 2: [6]} and rec.window_s >= 0.2
+    e2e = driver.end_to_end(rec)
+    assert e2e["prompt_tok_s"] == pytest.approx(50 / rec.window_s)
+    assert e2e["output_tok_s"] == pytest.approx(3 / rec.window_s)
+    assert rec.served[1] == [5, 7, 9] and rec.finished == {1}

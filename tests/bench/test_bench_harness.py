@@ -11,7 +11,7 @@ import sys
 
 import pytest
 
-from bench import peaks, run
+from bench import peaks, run, trace
 
 ROOT = run.ROOT
 SPEC = run.load_spec()
@@ -43,6 +43,8 @@ def test_cells_report_their_metrics():
     e2e = {c: {m["name"] for m in run.cell_parts(SPEC, c)["end_to_end"]}
            for c in CELLS}
     assert e2e["reduce-f32.stream"] == {"prim_GBps", "setup_s"}
+    assert e2e["glm4-9b.prefill-heavy"] == {"prompt_tok_s", "itl_p95_ms",
+                                            "setup_s"}
     for cell in CELLS:
         for m in run.cell_parts(SPEC, cell)["per_layer"]:
             assert m["moves"] in e2e[cell]
@@ -83,6 +85,8 @@ def test_benchmark_json_keeps_its_contract():
             "lower", "higher")
     for m in SPEC["per_layer"]:
         assert NAME.match(m["name"]) and m["moves"] in e2e
+        assert m["source"] in ("device_trace", "program_span",
+                               "program_counter", "host_clock")
         assert set(m) <= {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
         for cell in m["workloads"]:
@@ -139,6 +143,38 @@ def test_roofline_and_mfu_stay_at_or_under_100_when_work_fits_the_time():
                                        "decode.mfu_pct.py"), "t_m")
     ctx["work"] = {"flops": 197e12}
     assert mfu.read(ctx) == pytest.approx(50.0)
+    # Two decode programs of 1 s and 3 s (median 2 s); two steps that
+    # must each read 819 GB (1 s at peak) and do 98.5 TFLOP (0.5 s).
+    plane = "/device:TPU:0"
+    ctx["trace"].update(plane=plane, lo=0.0, hi=1e10, events=[
+        trace.Event(plane, trace.MODULES_LINE, "jit_decode(7)", s, e)
+        for s, e in ((0.0, 1e9), (2e9, 5e9))])
+    ctx["work"] = {"decode_steps": 2, "decode_bytes": 2 * 819e9,
+                   "decode_flops": 197e12}
+    step = run.load_module(os.path.join(run.BENCH, "metrics",
+                                        "decode.step_roofline.py"), "t_s")
+    assert step.read(ctx) == pytest.approx(50.0)
+    ctx["trace"]["events"] = ctx["trace"]["events"][:1]
+    assert step.read(ctx) == pytest.approx(100.0)
+
+
+def test_decode_gaps_leave_out_those_with_an_admission():
+    # Decode steps end at 1, 3, 6 and 10 s; the next ones start 0.5,
+    # 2 and 0.25 s later, and an admission prefill runs in the second
+    # gap: the gaps read are 0.5 and 0.25 s.
+    plane = "/device:TPU:0"
+    ev = [trace.Event(plane, trace.MODULES_LINE, "jit_decode(7)", s, e)
+          for s, e in ((0.0, 1e9), (1.5e9, 3e9), (5e9, 6e9),
+                       (6.25e9, 10e9))]
+    ev.append(trace.Event(plane, trace.MODULES_LINE, "jit_prefill(3)",
+                          3.5e9, 4.5e9))
+    ctx = {"trace": {"events": ev, "plane": plane, "lo": 0.0,
+                     "hi": 1e10}}
+    gap = run.load_module(os.path.join(run.BENCH, "metrics",
+                                       "prefill.decode_gap_ms.py"), "t_g")
+    assert gap.read(ctx) == pytest.approx(375.0)
+    ctx["trace"]["events"] = ev[2:3] + ev[4:]
+    assert gap.read(ctx) is None
 
 
 def test_a_configuration_without_limits_is_refused():
@@ -147,5 +183,13 @@ def test_a_configuration_without_limits_is_refused():
                                   "why": "test"}],
                 configs=[{"name": "glm4-9b",
                           "file": "bench/configs/glm4-9b.json"}])
+
+    def without_limits(path):
+        with open(path) as f:
+            cfg = json.load(f)
+        cfg.pop("limits", None)
+        return cfg
+
     with pytest.raises(ValueError, match="no limits"):
-        run.run_cell(spec, "t.cell", 1, 1.0, False, require_chip=False)
+        run.run_cell(spec, "t.cell", 1, 1.0, False, require_chip=False,
+                     load_config=without_limits)

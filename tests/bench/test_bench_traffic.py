@@ -1,6 +1,6 @@
 """Every traffic file (bench/traffic/*.json) through the one generator:
 deterministic per seed, within its parameters, and the same work for
-every seed in another order."""
+every seed (requests in one order, calls shuffled by the seed)."""
 
 import collections
 import glob
@@ -79,6 +79,18 @@ def test_every_seed_gets_the_same_work(name):
         (len(r["prompt"]), r["max_new"]) for r in rs)
     assert key(generator.requests(mix, 1, VOCAB)) == \
         key(generator.requests(mix, BIG_SEED, VOCAB))
+
+
+@pytest.mark.parametrize("name", [m for m in MIXES if generator.load_mix(
+    m)["kind"] == "requests"])
+def test_requests_come_in_one_order_for_every_seed(name):
+    mix = generator.load_mix(name)
+    sizes = lambda rs: [(len(r["prompt"]), r["max_new"])  # noqa: E731
+                        for r in rs]
+    a = generator.requests(mix, 1, VOCAB, rounds=3)
+    b = generator.requests(mix, BIG_SEED, VOCAB, rounds=3)
+    assert sizes(a) == sizes(b)
+    assert not np.array_equal(a[0]["prompt"], b[0]["prompt"])
 
 
 def test_small_calls_draw_sizes_uniformly_per_round():
