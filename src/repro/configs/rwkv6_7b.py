@@ -1,6 +1,11 @@
 """rwkv6-7b [ssm] — "Finch": 32L d_model=4096 (attention-free, 64 heads
 of size 64) d_ff=14336 vocab=65536, data-dependent decay.
-[arXiv:2404.05892; hf]"""
+[arXiv:2404.05892; hf]
+
+At hidden 4096 the published Finch uses the wider LoRAs of its
+modelling code (RWKV-LM v6 ``model.py``, HF ``modeling_rwkv6.py``):
+``TIME_MIX_EXTRA_DIM`` 64 (``time_maa_w1`` 4096 x 320) and
+``TIME_DECAY_EXTRA_DIM`` 128; smaller widths use 32 and 64."""
 
 import dataclasses
 
@@ -19,7 +24,7 @@ FULL = ModelConfig(
     pattern=("rwkv",),
     norm_type="layernorm",
     tie_embeddings=False,
-    rwkv=RWKVConfig(head_size=64, lora_decay=64, lora_mix=32),
+    rwkv=RWKVConfig(head_size=64, lora_decay=128, lora_mix=64),
 )
 
 SMOKE = dataclasses.replace(

@@ -37,6 +37,11 @@ where ``feat'`` is the slot view ``(cap, layers, *feat)`` with the
 token axis moved first — token position ``t`` of slot ``s`` lives at
 ``(table[s, t // page_size], t % page_size)``.
 
+Each eager copy of a dense per-slot leaf (``write_slot``,
+``write_token``) writes a new copy of the whole leaf; it adds the
+leaf's bytes to counter ``store.state_bytes`` (``repro.obs``), keyed by
+the leaf's path, from the shape alone: no device work, no sync.
+
 The allocator enforces the scheduler's slot-lifecycle invariants
 (``alloc_slot`` on a live slot and ``free_slot`` / ``write`` on a free
 one raise), which is what the admit/evict tests probe.
@@ -51,6 +56,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.precision import as_policy, two_sum
 from repro.models.transformer import _CACHE_LEAF_AXES
 
@@ -313,9 +319,7 @@ class PagedKVCache:
             # dense per-slot leaf (cross-attn memory, recurrent
             # state): copy the admission batch row into the slot row
             row = jnp.take(src, 0, axis=axis)
-            self._dense[path] = arr.at[
-                (slice(None),) * axis + (slot,)].set(
-                    row.astype(arr.dtype))
+            self._copy_row(path, arr, axis, slot, row)
 
     def write_token(self, caches, slot: int, position: int) -> None:
         """Write one freshly-decoded token's KV for ``slot``.
@@ -349,9 +353,14 @@ class PagedKVCache:
             if axis is None:
                 continue
             row = jnp.take(leaves[path], slot, axis=axis)
-            self._dense[path] = arr.at[
-                (slice(None),) * axis + (slot,)].set(
-                    row.astype(arr.dtype))
+            self._copy_row(path, arr, axis, slot, row)
+
+    def _copy_row(self, path, arr, axis: int, slot: int, row) -> None:
+        """Set ``slot``'s row of dense leaf ``path``: an eager update
+        that writes a new copy of the whole leaf."""
+        self._dense[path] = arr.at[
+            (slice(None),) * axis + (slot,)].set(row.astype(arr.dtype))
+        obs.count("store.state_bytes", "/".join(path), arr.nbytes)
 
     # ------------------------------------------------------- reads
 

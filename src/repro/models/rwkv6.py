@@ -5,7 +5,23 @@ Per head (size hs), state S in R^{hs x hs}:
     y_t = r_t^T (S_{t-1} + diag(u) k_t v_t^T)
     S_t = diag(w_t) S_{t-1} + k_t v_t^T
 with w_t = exp(-exp(decay_base + LoRA(x-shifted))) — the data-dependent
-decay that distinguishes Finch from RWKV-5.
+decay that distinguishes Finch from RWKV-5.  w_t lies in (0, 1] by
+construction, so the decay exponent is not clipped.
+
+It follows the published Finch forward (RWKV-LM's v6 ``model.py`` and
+the HF ``modeling_rwkv6.py``): the five-way ddlerp token shift, the
+decay LoRA, the bonus ``u``, a per-head GroupNorm (``ln_x``) with eps
+``1e-5 * head_size_divisor**2`` and a SiLU gate; the channel-mix is a
+squared-ReLU MLP behind its own token shift and a sigmoid receptance.
+The LayerNorm the published model applies to the embeddings before
+block 0 (``ln0``) is ``embed_norm`` in ``repro.models.transformer``.
+HF's ``rescale_every`` (halving the residual every 6 layers, with the
+output weights scaled to match) is an fp16 overflow guard that leaves
+the function unchanged, and is not applied.
+
+Named scopes mark the parts in the compiled program: ``rwkv.time_mix``
+(the whole time-mix), inside it ``rwkv.wkv`` (the recurrence alone),
+and ``rwkv.channel_mix``.
 
 Training runs the WKV recurrence as a lax.scan over time (compile-size
 O(1) in sequence length); decode is a single state update.  The state is
@@ -23,6 +39,9 @@ from repro.distributed.sharding import constrain
 from repro.models.param import Param
 
 MIX_NAMES = ("w", "k", "v", "r", "g")
+# ln_x's eps: 1e-5 * head_size_divisor**2, with head_size_divisor 8 in
+# every published Finch configuration.
+LN_X_EPS = 1e-5 * 8 ** 2
 
 
 def timemix_specs(cfg):
@@ -182,7 +201,7 @@ def _wkv_chunked(r, k, v, w, u, state0, *, chunk: int = 32):
     return y, s_final
 
 
-def _group_norm(y, scale, bias, n_heads, eps=1e-5):
+def _group_norm(y, scale, bias, n_heads, eps=LN_X_EPS):
     """Per-head LayerNorm over head_size (RWKV's ln_x)."""
     b, s, d = y.shape
     yh = y.reshape(b, s, n_heads, -1).astype(jnp.float32)
@@ -194,6 +213,7 @@ def _group_norm(y, scale, bias, n_heads, eps=1e-5):
     return out
 
 
+@jax.named_scope("rwkv.time_mix")
 def time_mix(params, cfg, x, state):
     """x: (B,S,D). state: see make_state. Returns (out, new_state)."""
     dt = x.dtype
@@ -207,10 +227,9 @@ def time_mix(params, cfg, x, state):
 
     decay_mod = jnp.tanh(xw @ params["decay_w1"].astype(dt)) \
         @ params["decay_w2"].astype(dt)
-    logw = -jnp.exp(jnp.clip(
-        params["decay_base"].astype(jnp.float32)
-        + decay_mod.astype(jnp.float32), -10.0, 8.0))
-    w = jnp.exp(logw)                                        # (B,S,D) in (0,1)
+    logw = -jnp.exp(params["decay_base"].astype(jnp.float32)
+                    + decay_mod.astype(jnp.float32))
+    w = jnp.exp(logw)                                        # (B,S,D) in (0,1]
 
     r = (xr @ params["wr"].astype(dt)).reshape(b, s, n, -1)
     k = (xk @ params["wk"].astype(dt)).reshape(b, s, n, -1)
@@ -219,14 +238,15 @@ def time_mix(params, cfg, x, state):
     wh = w.reshape(b, s, n, -1)
 
     chunk = getattr(cfg, "rwkv_chunk", 0)
-    if chunk and s % chunk == 0 and s > chunk:
-        y, new_wkv = _wkv_chunked(r, k, v, wh,
-                                  params["bonus"].astype(jnp.float32),
-                                  state["wkv"], chunk=chunk)
-    else:
-        y, new_wkv = _wkv_scan(r, k, v, wh,
-                               params["bonus"].astype(jnp.float32),
-                               state["wkv"])
+    with jax.named_scope("rwkv.wkv"):
+        if chunk and s % chunk == 0 and s > chunk:
+            y, new_wkv = _wkv_chunked(r, k, v, wh,
+                                      params["bonus"].astype(jnp.float32),
+                                      state["wkv"], chunk=chunk)
+        else:
+            y, new_wkv = _wkv_scan(r, k, v, wh,
+                                   params["bonus"].astype(jnp.float32),
+                                   state["wkv"])
     y = _group_norm(y.reshape(b, s, d), params["ln_x_scale"],
                     params["ln_x_bias"], n)
     out = (y.astype(dt) * g) @ params["wo"].astype(dt)
@@ -234,6 +254,7 @@ def time_mix(params, cfg, x, state):
     return constrain(out, ("batch", None, None)), new_state
 
 
+@jax.named_scope("rwkv.channel_mix")
 def channel_mix(params, cfg, x, state):
     dt = x.dtype
     x_prev = _shifted(x, state["x_cm"].astype(dt))

@@ -331,6 +331,9 @@ def backbone_specs(cfg):
 def decoder_specs(cfg):
     specs = {"embed": L.embed_specs(cfg.vocab_size, cfg.d_model),
              **backbone_specs(cfg)}
+    if cfg.rwkv is not None:
+        # RWKV's ln0: a LayerNorm on the embeddings before block 0.
+        specs["embed_norm"] = L.norm_specs(cfg.d_model, cfg.norm_type)
     if not cfg.tie_embeddings:
         specs["lm_head"] = Param((cfg.d_model, cfg.vocab_size),
                                  ("embed", "vocab"))
@@ -356,6 +359,8 @@ def decoder_forward(params, cfg, tokens, *, positions=None, caches=None,
             d=cfg.d_model, compute_dtype=cfg.compute_dtype,
             cast_table=getattr(cfg, "bf16_activation_ar", False),
             onehot=getattr(cfg, "onehot_embed", False))
+    if "embed_norm" in params:
+        x = _norm(params["embed_norm"], x, cfg)
     s = x.shape[1]
     if positions is None:
         positions = jnp.arange(s, dtype=jnp.int32)

@@ -28,6 +28,10 @@ autotuner's candidate runs (``dispatch.execute``) are not counted.
 ``resolve_method`` counts ``dispatch.fallbacks`` keyed
 ``(op, requested, served)`` each time it substitutes its fallback: a
 jitted caller once per trace.
+
+The paged KV store (``repro.models.kv_cache``) counts
+``store.state_bytes`` keyed by leaf path: the bytes of each eager copy
+of a dense per-slot leaf (recurrent state, cross-attention memory).
 """
 
 from __future__ import annotations
